@@ -1,148 +1,50 @@
-"""JAX version-compatibility layer.
+"""JAX API shims for the installed JAX (>= 0.9).
 
-Every version-sensitive JAX call in this repo goes through here so the
-model / sharding / roofline stack runs unchanged across the JAX releases we
-support.  The two API generations we bridge:
+The model / sharding / roofline stack reaches a few JAX APIs whose defaults
+or return shapes are easy to get wrong; they go through here so each
+decision is made once:
 
-* **JAX 0.4.x** (tested on 0.4.37): ``shard_map`` lives at
-  ``jax.experimental.shard_map.shard_map`` and takes ``check_rep=``;
-  ``jax.make_mesh`` appeared in 0.4.35.
-* **JAX 0.5+**: ``shard_map`` is the top-level ``jax.shard_map`` and the
-  replication-check kwarg was renamed to ``check_vma=``.
+* :func:`make_mesh` builds **Auto** mesh axes.  ``jax.make_mesh`` defaults
+  to Explicit axes, on which ``with_sharding_constraint`` (the rules'
+  ``constrain``) and the GSPMD-propagated gathers of the model refuse to
+  run.
+* :func:`cost_analysis` turns ``Compiled.cost_analysis()`` into a dict,
+  empty where the backend provides nothing.
+* :func:`shard_map_body` / :func:`shard_map_mesh_size` read a shard_map
+  equation's params for the roofline jaxpr walker.
 
-Supported-version policy
-------------------------
-The floor is **jax >= 0.4.30** (the oldest release the fallbacks below
-target) and the intent is that the latest stable release always works: new
-call-site breakage belongs in this module, not in the call sites.  Callers
-always use the *new* spelling (``compat.shard_map(..., check_vma=...)``);
-this layer down-translates for older installs.  Anything not wrapped here
-is believed stable across the supported range (``jax.jit``, ``jax.lax.*``,
-``jax.tree.*``, ``jax.sharding.Mesh`` / ``PartitionSpec``).
-
-The resolver is cached; tests monkeypatch the probe functions and call
-:func:`reset` to exercise both import paths on a single installed version.
+``shard_map`` and ``axis_size`` need no shim: call ``jax.shard_map`` and
+``jax.lax.axis_size``.
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Any, Callable, Optional
+from typing import Any, Optional
 
 import jax
-
-# cached (callable, source) — populated lazily by resolve_shard_map()
-_SHARD_MAP: Optional[tuple[Callable, str]] = None
+from jax.sharding import AxisType
 
 
-def reset() -> None:
-    """Drop cached resolutions (test hook, used after monkeypatching)."""
-    global _SHARD_MAP
-    _SHARD_MAP = None
-
-
-def _locate_shard_map() -> tuple[Callable, str]:
-    """Find the installed shard_map implementation.
-
-    Prefers the top-level ``jax.shard_map`` (0.5+); falls back to
-    ``jax.experimental.shard_map.shard_map`` (0.4.x).
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, "jax.shard_map"
-    try:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-    except ImportError as e:  # pragma: no cover - no supported impl at all
-        raise ImportError(
-            "No shard_map implementation found: need either jax.shard_map "
-            "(jax >= 0.5) or jax.experimental.shard_map (jax 0.4.x); "
-            f"installed jax is {jax.__version__}") from e
-    return fn, "jax.experimental.shard_map"
-
-
-def resolve_shard_map() -> tuple[Callable, str]:
-    """-> (shard_map callable, dotted source path), cached."""
-    global _SHARD_MAP
-    if _SHARD_MAP is None:
-        _SHARD_MAP = _locate_shard_map()
-    return _SHARD_MAP
-
-
-def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_vma: Optional[bool] = None, **kwargs) -> Callable:
-    """Version-portable ``shard_map``.
-
-    Callers use the modern kwarg spelling (``check_vma``); on 0.4.x installs
-    it is translated to ``check_rep``.  Unknown extra kwargs are passed only
-    if the resolved implementation accepts them, so a call site written for
-    a newer JAX degrades gracefully on an older one.
-    """
-    fn, _src = resolve_shard_map()
-    try:
-        params = inspect.signature(fn).parameters
-        accepts_kw = any(p.kind is inspect.Parameter.VAR_KEYWORD
-                         for p in params.values())
-    except (TypeError, ValueError):  # exotic wrappers: trust the caller
-        params, accepts_kw = {}, True
-    if check_vma is not None:
-        if "check_vma" in params or accepts_kw:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check_vma
-    if not accepts_kw:
-        kwargs = {k: v for k, v in kwargs.items() if k in params}
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def axis_size(axis_name):
-    """Size of a mesh axis from inside a shard_map body.
-
-    ``jax.lax.axis_size`` only exists on newer JAX; on 0.4.x the classic
-    ``psum(1, axis)`` idiom returns the size as a static int.
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` (0.4.35+) with a manual fallback for older JAX."""
-    fn = getattr(jax, "make_mesh", None)
-    if fn is not None:
-        return fn(axis_shapes, axis_names)
-    import math
-
-    import numpy as np
-    from jax.sharding import Mesh
-
-    n = math.prod(axis_shapes)
-    devs = np.asarray(jax.devices()[:n]).reshape(axis_shapes)
-    return Mesh(devs, axis_names)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def cost_analysis(compiled) -> dict:
-    """Normalized ``Compiled.cost_analysis()``.
-
-    JAX 0.4.x returns a one-element list of dicts (per partition); newer
-    releases return the dict directly (or None when XLA provides nothing).
-    """
+    """``Compiled.cost_analysis()`` as a dict ({} when XLA provides none)."""
     try:
         cost = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 - backend may not implement it
         return {}
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    return dict(cost) if cost else {}
 
 
 # ----- jaxpr-level shard_map introspection (roofline walker) -----
 
-# primitive param key holding the body jaxpr has been "jaxpr" throughout
-# the supported range, but keep a search list like _SUBJAXPR_KEYS so a
-# rename only needs updating here.
+# primitive param key holding the body jaxpr; a search list so a rename
+# only needs updating here.
 _SHARD_MAP_BODY_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
 
 
@@ -159,11 +61,9 @@ def shard_map_body(params: dict) -> Optional[Any]:
 
 
 def shard_map_mesh_size(params: dict) -> int:
-    """Total device count of a shard_map equation's mesh.
-
-    Works for both concrete ``Mesh`` (0.4.x traces) and ``AbstractMesh``
-    (newer traces): both expose ``.size`` or an axis-name->size ``shape``.
-    """
+    """Total device count of a shard_map equation's mesh (concrete ``Mesh``
+    or ``AbstractMesh``: both expose ``.size`` or an axis-name->size
+    ``shape``)."""
     import math
 
     mesh = params.get("mesh")

@@ -24,8 +24,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, st_ref,
-                state_scr, *, chunk: int, seq_len: int):
+def _ssd_kernel(x_ref, dt_ref, dtr_ref, a_ref, b_ref, c_ref, d_ref, y_ref,
+                st_ref, state_scr, *, chunk: int, seq_len: int, heads: int):
+    hi = pl.program_id(0) % heads
     ci = pl.program_id(1)
     nc = pl.num_programs(1)
 
@@ -33,8 +34,13 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, st_ref,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
+    # dt arrives twice, as a column [L, 1] and as a row [1, L]: the chip's
+    # vector unit reduces along either axis but cannot cheaply transpose a
+    # one-wide vector, and the decay matrix needs the cumulative sum both
+    # ways round.
     x = x_ref[0].astype(jnp.float32)        # [L, P]
     dt = dt_ref[0].astype(jnp.float32)      # [L, 1]
+    dtr = dtr_ref[0].astype(jnp.float32)    # [1, L]
     # EXACT pad masking (the same discipline flash_attention applies
     # with its `kpos < seq_k` mask): zero dt at padded positions, so a
     # padded step contributes nothing to the intra-chunk quadratic
@@ -45,22 +51,29 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, st_ref,
     # by an epsilon that scales with the pad count.
     pos = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
     dt = jnp.where(pos < seq_len, dt, 0.0)
-    a = a_ref[0, 0]                          # scalar (negative)
+    posr = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+    dtr = jnp.where(posr < seq_len, dtr, 0.0)
+    a = a_ref[hi, 0]                         # scalar (negative)
     bm = b_ref[0].astype(jnp.float32)       # [L, N]
     cm = c_ref[0].astype(jnp.float32)       # [L, N]
-    dD = d_ref[0, 0]                         # scalar
+    dD = d_ref[hi, 0]                        # scalar
 
-    dA = dt * a                              # [L, 1]
-    cs = jnp.cumsum(dA, axis=0)              # [L, 1]
+    # inclusive cumulative sums of dA = dt * a, as a column and as a row
+    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = li >= lj
+    cs = jnp.sum(jnp.where(causal, dtr * a, 0.0), axis=1,
+                 keepdims=True)              # [L, 1]
+    csr = jnp.sum(jnp.where(causal, 0.0, dt * a), axis=0,
+                  keepdims=True) + dtr * a   # [1, L]
+    cs_last = jnp.sum(dtr * a, axis=1, keepdims=True)  # [1, 1]
 
     # ---- intra-chunk (masked decay-weighted quadratic) ----
     scores = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)  # [L, L] = C_i . B_j
-    li = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-    lj = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    decay = jnp.where(li >= lj, jnp.exp(cs - cs.reshape(1, -1)), 0.0)
-    pmat = scores * decay * dt.reshape(1, -1)  # weight column j by dt_j
+    decay = jnp.where(causal, jnp.exp(cs - csr), 0.0)
+    pmat = scores * decay * dtr              # weight column j by dt_j
     y = jax.lax.dot_general(pmat, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [L, P]
 
@@ -75,12 +88,11 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, st_ref,
     y_ref[0] = y.astype(y_ref.dtype)
 
     # ---- state update ----
-    cs_last = cs[chunk - 1]                  # [1]
-    w = jnp.exp(cs_last[None, :] - cs) * dt  # [L, 1]
+    w = jnp.exp(cs_last - cs) * dt           # [L, 1]
     st_add = jax.lax.dot_general(
         x * w, bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)  # [P, N]
-    state_scr[...] = st * jnp.exp(cs_last[0]) + st_add
+    state_scr[...] = st * jnp.exp(cs_last) + st_add
 
     @pl.when(ci == nc - 1)
     def _emit_state():
@@ -111,6 +123,7 @@ def ssd_chunked_kernel(x, dt, A, B, C, D, *, chunk: int = 256,
 
     xf = x.transpose(0, 2, 1, 3).reshape(b * h, sp, p)
     dtf = dt.transpose(0, 2, 1).reshape(b * h, sp, 1)
+    dtr = dtf.reshape(b * h, 1, sp)
     bf = B.transpose(0, 2, 1, 3).reshape(b * g, sp, n)
     cf = C.transpose(0, 2, 1, 3).reshape(b * g, sp, n)
     af = A.reshape(h, 1).astype(jnp.float32)
@@ -123,22 +136,26 @@ def ssd_chunked_kernel(x, dt, A, B, C, D, *, chunk: int = 256,
         bi, hi = bh // h, bh % h
         return (bi * g + hi // hg, ci, 0)
 
-    def amap(bh, ci):
-        return (bh % h, 0)
+    def rowmap(bh, ci):
+        return (bh, 0, ci)
 
     def stmap(bh, ci):
         return (bh, 0, 0)
 
+    # A and D: [h, 1] per-head scalars, held whole in scalar memory and
+    # indexed by the grid's head (a (1, 1) VMEM block of an [h, 1] array
+    # breaks the TPU's (8, 128) tiling rule)
     y, st = pl.pallas_call(
-        functools.partial(_ssd_kernel, chunk=chunk, seq_len=s),
+        functools.partial(_ssd_kernel, chunk=chunk, seq_len=s, heads=h),
         grid=(b * h, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, p), xmap),
             pl.BlockSpec((1, chunk, 1), xmap),
-            pl.BlockSpec((1, 1), amap),
+            pl.BlockSpec((1, 1, chunk), rowmap),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, n), bcmap),
             pl.BlockSpec((1, chunk, n), bcmap),
-            pl.BlockSpec((1, 1), amap),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, p), xmap),
@@ -150,7 +167,7 @@ def ssd_chunked_kernel(x, dt, A, B, C, D, *, chunk: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(xf, dtf, af, bf, cf, df)
+    )(xf, dtf, dtr, af, bf, cf, df)
 
     y = y.reshape(b, h, sp, p).transpose(0, 2, 1, 3)[:, :s]
     st = st.reshape(b, h, p, n)

@@ -1,5 +1,7 @@
 """Serving driver: BootSeer-managed startup, then a batched serving session
-with the ServeEngine (prefill + decode over a shared cache).
+with the ServeEngine (prefill + decode over a shared cache), at the
+architecture's published widths (``--tiny``: the reduced variant, for CPU
+runs and tests).
 
     PYTHONPATH=src python -m repro.launch.serve --arch mamba2-370m \
         --requests 6 --new-tokens 16 --workdir /tmp/bootseer_serve
@@ -12,6 +14,7 @@ the paper's many-short-jobs workload (§4, "feature testing" jobs).
 from __future__ import annotations
 
 import argparse
+import tempfile
 import time
 from pathlib import Path
 
@@ -20,27 +23,35 @@ import numpy as np
 
 from repro.blockstore.registry import Registry
 from repro.ckpt.checkpoint import Checkpointer
-from repro.configs import ARCHS, get_tiny
+from repro.configs import ARCHS, get_config, get_tiny
 from repro.core.bootseer import BootseerRuntime, JobSpec
 from repro.core.stages import Stage
 from repro.dfs.hdfs import HdfsCluster, ThrottleModel
-from repro.launch.train import ensure_image
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.train import device_summary, ensure_image, stage_seconds
 from repro.models.model import Model
 from repro.serve.engine import Request, ServeEngine
 from repro.sharding.rules import single_device_rules
 
 
-def main():
+def main(argv=None) -> dict:
+    """Run startup then a serving session; returns a summary (device,
+    startup stage seconds, requests served with their prompts and
+    generated tokens)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-370m", choices=list(ARCHS))
+    ap.add_argument("--tiny", action="store_true",
+                    help="reduced same-family config (CPU runs and tests)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--cache-len", type=int, default=128)
     ap.add_argument("--nodes", type=int, default=2)
-    ap.add_argument("--workdir", default="/tmp/bootseer_serve")
+    ap.add_argument("--workdir",
+                    default=str(Path(tempfile.gettempdir()) / "bootseer_serve"))
     ap.add_argument("--no-bootseer", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     root = Path(args.workdir)
     root.mkdir(parents=True, exist_ok=True)
@@ -59,13 +70,12 @@ def main():
     rt = BootseerRuntime(registry=reg, hdfs=hdfs, workdir=root / "rt",
                          optimize=not args.no_bootseer)
     res = rt.run_startup(spec)
-    for st in (Stage.IMAGE_LOAD, Stage.ENV_SETUP):
-        mx = max(d.get(st.value, 0) for d in res.node_stage_s.values())
-        print(f"startup {st.value:<12} {mx:6.2f}s")
-    print(f"startup TOTAL        {res.total_s:6.2f}s "
-          f"({'warm' if res.notes.get('prefetch_used') else 'cold'})")
+    startup = stage_seconds(res, (Stage.IMAGE_LOAD, Stage.ENV_SETUP))
+    for name, sec in startup.items():
+        print(f"startup {name:<12} {sec:6.2f}s")
+    print(f"startup was {'warm' if res.notes.get('prefetch_used') else 'cold'}")
 
-    cfg = get_tiny(args.arch)
+    cfg = get_tiny(args.arch) if args.tiny else get_config(args.arch)
     model = Model(cfg, single_device_rules())
     # serving params live in a checkpoint: the first invocation seeds it,
     # warm restarts restore through the planned path under the runtime's
@@ -92,17 +102,25 @@ def main():
                     max_new_tokens=args.new_tokens,
                     temperature=0.7 if i % 2 else 0.0)
             for i in range(args.requests)]
+    served = []
     t0 = time.perf_counter()
-    done = 0
     while todo:
-        batch_reqs = todo[:args.batch]
-        todo = todo[args.batch:]
-        out = engine.generate(batch_reqs)
-        for r in out[:len(batch_reqs)]:
-            done += len(r.generated)
+        n = min(args.batch, len(todo))
+        served += engine.generate(todo[:n])[:n]  # generate() pads the list
+        todo = todo[n:]
     dt = time.perf_counter() - t0
-    print(f"served {args.requests} requests, {done} tokens "
-          f"in {dt:.2f}s ({done / dt:.1f} tok/s on CPU)")
+    rt.drain_deferred()   # surface deferred stream failures
+    done = sum(len(r.generated) for r in served)
+    device = device_summary()
+    print(f"served {len(served)} requests, {done} tokens in {dt:.2f}s "
+          f"({done / dt:.1f} tok/s on {device['count']} x "
+          f"{device['kind']})")
+    return {"arch": cfg.name, "device": device, "startup_s": startup,
+            "serve_s": dt,
+            "requests": [{"prompt": r.prompt.tolist(),
+                          "temperature": r.temperature,
+                          "max_new_tokens": r.max_new_tokens,
+                          "generated": list(r.generated)} for r in served]}
 
 
 if __name__ == "__main__":

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import layers as L
@@ -232,7 +231,7 @@ class Model:
                 return out
             # build this shard's rows of the cache from the gathered k/v
             w, s = cache_w, seq_len
-            w_loc = w // compat.axis_size(tp)
+            w_loc = w // jax.lax.axis_size(tp)
             my0 = jax.lax.axis_index(tp) * w_loc
             g = my0 + jnp.arange(w_loc)
             p_start = max(0, s - w)
@@ -251,8 +250,8 @@ class Model:
                          P(dp, tp, None, None), P(tp))
         else:
             out_specs = P(dp, tp, None, None)
-        return compat.shard_map(body, mesh=r.mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_vma=False)
+        return jax.shard_map(body, mesh=r.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _decode_attn(self, batch: int):
         """One-token decode with distributed online softmax over the
@@ -307,8 +306,8 @@ class Model:
                     P(dp, axes, None, None), P(axes), P())
         out_specs = (P(dp, None, None, None), P(dp, axes, None, None),
                      P(dp, axes, None, None), P(axes))
-        return compat.shard_map(body, mesh=r.mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_vma=False)
+        return jax.shard_map(body, mesh=r.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # ----- attention sublayer -----
 

@@ -30,7 +30,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import MoEConfig
 
 
@@ -114,7 +113,7 @@ def moe_block_local(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
     sg = gate.reshape(-1)[order]
 
     if moe_sharding == "ep":
-        n_shards = compat.axis_size(model_axis)
+        n_shards = jax.lax.axis_size(model_axis)
         rank = jax.lax.axis_index(model_axis)
         e_loc = e // n_shards
         off = rank * e_loc
@@ -177,7 +176,7 @@ def make_sharded_moe(mesh, *, moe: MoEConfig, model_axis: str = "model",
         y, aux = body(xbsd.reshape(b * s, d), rw, wg, wu, wd)
         return y.reshape(b, s, d), aux
 
-    return compat.shard_map(
+    return jax.shard_map(
         flat_body,
         mesh=mesh,
         in_specs=(P(batch_spec, None, None), P(None, None),

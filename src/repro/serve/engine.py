@@ -30,7 +30,10 @@ class ServeEngine:
     def __init__(self, model: Model, params, *, batch: int, cache_len: int,
                  tune_profile=None):
         self.model = model
-        self.params = params
+        # placed once with the steps' shardings: host arrays (a restored
+        # checkpoint) would otherwise be copied to the device on every call
+        self.params = jax.device_put(
+            params, model.rules.named_tree(model.param_specs()))
         self.batch = batch
         self.cache_len = cache_len
         # kernel launch configs for this replica: installed as the

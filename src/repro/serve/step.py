@@ -12,11 +12,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models.model import Model
 
 
-def _named(model: Model, tree):
-    r = model.rules
-    return jax.tree.map(r.named, tree, is_leaf=lambda x: isinstance(x, P))
-
-
 def jit_prefill(model: Model, batch: int, cache_len: int, *,
                 with_embeddings: bool = False, with_mrope: bool = False):
     r = model.rules
@@ -36,9 +31,9 @@ def jit_prefill(model: Model, batch: int, cache_len: int, *,
 
     return jax.jit(
         fn,
-        in_shardings=(_named(model, pspecs), _named(model, bspecs)),
+        in_shardings=(r.named_tree(pspecs), r.named_tree(bspecs)),
         out_shardings=(r.named(P(dp, r.tp(model.cfg.vocab_size))),
-                       _named(model, cspecs)),
+                       r.named_tree(cspecs)),
     )
 
 
@@ -50,9 +45,9 @@ def jit_decode_step(model: Model, batch: int, cache_len: int, *,
     cspecs = model.cache_specs(batch, cache_len)
     return jax.jit(
         model.decode_step,
-        in_shardings=(_named(model, pspecs), r.named(P(dp, None)),
-                      _named(model, cspecs), r.named(P())),
+        in_shardings=(r.named_tree(pspecs), r.named(P(dp, None)),
+                      r.named_tree(cspecs), r.named(P())),
         out_shardings=(r.named(P(dp, r.tp(model.cfg.vocab_size))),
-                      _named(model, cspecs)),
+                      r.named_tree(cspecs)),
         donate_argnums=(2,) if donate_cache else (),
     )

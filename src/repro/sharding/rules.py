@@ -125,6 +125,11 @@ class Rules:
     def named(self, spec: P) -> NamedSharding:
         return NamedSharding(self.mesh, spec)
 
+    def named_tree(self, specs):
+        """NamedSharding pytree congruent with a PartitionSpec pytree."""
+        return jax.tree.map(self.named, specs,
+                            is_leaf=lambda x: isinstance(x, P))
+
     def constrain(self, x, spec: P):
         return jax.lax.with_sharding_constraint(x, self.named(spec))
 

@@ -11,7 +11,7 @@ import numpy as np
 
 from repro.models.model import Model
 from repro.optim.adamw import AdamWConfig, adamw_init
-from repro.train.step import jit_train_step
+from repro.train.step import jit_train_step, state_shardings
 
 
 def train_loop(model: Model, *, tune_profile=None, **kw):
@@ -60,25 +60,36 @@ def _train_loop(model: Model, *, batch: int, seq_len: int, steps: int,
     from repro.data.synthetic import SyntheticStream
 
     opt_cfg = opt_cfg or AdamWConfig()
-    if params is None:
-        params = model.init(jax.random.key(seed))
-    if opt_state is None:
-        opt_state = adamw_init(params)
-
     if resume_from is not None and checkpointer is None:
         raise ValueError(
             f"resume_from={resume_from} requires a checkpointer — without "
             "one the run would silently train from scratch")
+    # every tree is placed with the step's own shardings, so a restored
+    # or freshly initialized state is never held whole on one device
+    pshard, oshard = state_shardings(model)
+    if resume_from is None:
+        if params is None:
+            params = model.init(jax.random.key(seed))
+        if opt_state is None:
+            opt_state = adamw_init(params)
+        params, opt_state = jax.device_put((params, opt_state),
+                                           (pshard, oshard))
 
     opt_tail = None
     if resume_from is not None:
+        # restore targets: shapes only, so no device memory is spent on a
+        # state the checkpoint overwrites
+        if params is None:
+            params = jax.eval_shape(model.init, jax.random.key(seed))
+        if opt_state is None:
+            opt_state = jax.eval_shape(adamw_init, params)
         if restore_coords is None and restore_specs is not None:
             restore_coords = model.rules.coords_of_rank(0)
         params, opt_tail = checkpointer.restore_planned(
             resume_from, params, opt_state, specs=restore_specs,
             rules=model.rules, coords=restore_coords, async_tail=True,
             sched=restore_sched)
-        params = jax.tree.map(jax.numpy.asarray, params)
+        params = jax.device_put(params, pshard)
         start_step = resume_from
 
     step_fn = jit_train_step(model, opt_cfg, batch)
@@ -86,15 +97,15 @@ def _train_loop(model: Model, *, batch: int, seq_len: int, steps: int,
                            model.rules, batch, seq_len)
     if opt_tail is not None and steps > 0:
         # realize the overlap: jit is lazy, so drive the real compile with
-        # a discarded warmup step (opt_state is still the zero-initialized
-        # like tree — same shapes/dtypes, so the cache hit carries over)
-        # while the optimizer wave streams in the background.  The step
-        # donates its arguments, so warm up on a copy of the params.
-        step_fn(jax.tree.map(jax.numpy.copy, params), opt_state,
+        # a discarded warmup step on a zero optimizer state (same shapes,
+        # dtypes and shardings, so the cache hit carries over) while the
+        # optimizer wave streams in the background.  The step donates its
+        # arguments, so warm up on a copy of the params.
+        step_fn(jax.tree.map(jax.numpy.copy, params), adamw_init(params),
                 loader(start_step))
     if opt_tail is not None:
         (opt_state,) = opt_tail.result()
-        opt_state = jax.tree.map(jax.numpy.asarray, opt_state)
+        opt_state = jax.device_put(opt_state, oshard)
 
     history = []
     saves = 0       # saves this run: every full_every-th one is full

@@ -57,23 +57,25 @@ def make_train_step(model: Model, opt_cfg: AdamWConfig,
     return train_step
 
 
+def state_shardings(model: Model) -> tuple:
+    """(params, AdamW state) NamedSharding trees on the model's mesh."""
+    pspecs = model.param_specs()
+    return model.rules.named_tree(
+        (pspecs, {"mu": pspecs, "nu": pspecs, "step": P()}))
+
+
 def jit_train_step(model: Model, opt_cfg: AdamWConfig, batch: int,
                    lr_fn: Optional[Callable] = None, *, donate: bool = True,
                    with_embeddings: bool = False, with_mrope: bool = False):
     """Fully-specified jit of the train step for the model's mesh."""
-    r = model.rules
     step_fn = make_train_step(model, opt_cfg, lr_fn)
-    pspecs = model.param_specs()
-    ospecs = {"mu": pspecs, "nu": pspecs, "step": P()}
+    pshard, oshard = state_shardings(model)
     bspecs = batch_specs(model, batch, with_embeddings=with_embeddings,
                          with_mrope=with_mrope)
-    named = lambda tree: jax.tree.map(
-        r.named, tree, is_leaf=lambda x: isinstance(x, P))
-    mspec = P()
     return jax.jit(
         step_fn,
-        in_shardings=(named(pspecs), named(ospecs), named(bspecs)),
-        out_shardings=(named(pspecs), named(ospecs), None),
+        in_shardings=(pshard, oshard, model.rules.named_tree(bspecs)),
+        out_shardings=(pshard, oshard, None),
         donate_argnums=(0, 1) if donate else (),
     )
 

@@ -1,103 +1,30 @@
-"""repro.compat: JAX version-compat layer.
-
-The resolver must pick the top-level ``jax.shard_map`` when it exists
-(JAX 0.5+) and fall back to ``jax.experimental.shard_map`` (0.4.x), and the
-``check_vma`` kwarg must be down-translated to ``check_rep`` for the old
-API.  Both paths are exercised via monkeypatching so the suite covers them
-regardless of which JAX is installed.
-"""
+"""repro.compat: the JAX API shims run on the installed JAX."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
 
 
-@pytest.fixture(autouse=True)
-def _fresh_resolver():
-    """Each test resolves from scratch and leaves no cached fake behind."""
-    compat.reset()
-    yield
-    compat.reset()
-
-
-class TestResolution:
-    def test_prefers_top_level_shard_map(self, monkeypatch):
-        calls = {}
-
-        def fake_new(f, *, mesh, in_specs, out_specs, check_vma=True):
-            calls.update(mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=check_vma)
-            return f
-
-        monkeypatch.setattr(jax, "shard_map", fake_new, raising=False)
-        compat.reset()
-        fn, src = compat.resolve_shard_map()
-        assert src == "jax.shard_map"
-        out = compat.shard_map(lambda x: x, mesh="m", in_specs=P(),
-                               out_specs=P(), check_vma=False)
-        assert callable(out)
-        assert calls["check_vma"] is False
-        assert calls["mesh"] == "m"
-
-    def test_falls_back_to_experimental(self, monkeypatch):
-        monkeypatch.delattr(jax, "shard_map", raising=False)
-        compat.reset()
-        fn, src = compat.resolve_shard_map()
-        assert src == "jax.experimental.shard_map"
-
-    def test_check_vma_translated_to_check_rep(self, monkeypatch):
-        calls = {}
-
-        def fake_old(f, mesh, in_specs, out_specs, check_rep=True,
-                     auto=frozenset()):
-            calls.update(check_rep=check_rep)
-            return f
-
-        monkeypatch.delattr(jax, "shard_map", raising=False)
-        monkeypatch.setattr(compat, "_locate_shard_map",
-                            lambda: (fake_old, "jax.experimental.shard_map"))
-        compat.reset()
-        compat.shard_map(lambda x: x, mesh="m", in_specs=P(),
-                         out_specs=P(), check_vma=False)
-        assert calls["check_rep"] is False
-
-    def test_unknown_kwargs_dropped_for_old_api(self, monkeypatch):
-        seen = {}
-
-        def fake_old(f, mesh, in_specs, out_specs, check_rep=True):
-            seen["kwargs_ok"] = True
-            return f
-
-        monkeypatch.setattr(compat, "_locate_shard_map",
-                            lambda: (fake_old, "jax.experimental.shard_map"))
-        compat.reset()
-        # axis_names only exists on newer APIs: must not blow up the old one
-        compat.shard_map(lambda x: x, mesh="m", in_specs=P(),
-                         out_specs=P(), check_vma=True,
-                         axis_names={"data"})
-        assert seen["kwargs_ok"]
-
-
 class TestInstalledVersion:
-    """The resolved implementation actually runs on the installed JAX."""
+    """The shims, and the shard_map pattern the model uses, run on the
+    installed JAX."""
 
     def test_shard_map_executes(self, rules):
-        fn = compat.shard_map(lambda x: x * 2, mesh=rules.mesh,
-                              in_specs=P(None, None),
-                              out_specs=P(None, None), check_vma=False)
+        fn = jax.shard_map(lambda x: x * 2, mesh=rules.mesh,
+                           in_specs=P(None, None),
+                           out_specs=P(None, None), check_vma=False)
         y = fn(jnp.ones((4, 4)))
         np.testing.assert_allclose(np.asarray(y), 2 * np.ones((4, 4)))
 
     def test_axis_size_inside_body(self, rules):
         def body(x):
-            return x + compat.axis_size("model")
+            return x + jax.lax.axis_size("model")
 
-        fn = compat.shard_map(body, mesh=rules.mesh, in_specs=P(None, None),
-                              out_specs=P(None, None), check_vma=False)
+        fn = jax.shard_map(body, mesh=rules.mesh, in_specs=P(None, None),
+                           out_specs=P(None, None), check_vma=False)
         y = fn(jnp.zeros((2, 2)))
         # single-device mesh: model axis has size 1
         np.testing.assert_allclose(np.asarray(y), np.ones((2, 2)))
@@ -108,9 +35,9 @@ class TestInstalledVersion:
         assert mesh.shape["data"] == 1 and mesh.shape["model"] == 1
 
     def test_shard_map_jaxpr_helpers(self, rules):
-        fn = compat.shard_map(lambda x: x @ x, mesh=rules.mesh,
-                              in_specs=P(None, None),
-                              out_specs=P(None, None), check_vma=False)
+        fn = jax.shard_map(lambda x: x @ x, mesh=rules.mesh,
+                           in_specs=P(None, None),
+                           out_specs=P(None, None), check_vma=False)
         closed = jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((4, 4), jnp.float32))
         eqn = next(e for e in closed.jaxpr.eqns
                    if e.primitive.name == "shard_map")

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.roofline.jaxpr_cost import analytic_cost, jaxpr_cost
 
 
@@ -68,9 +67,9 @@ class TestWalker:
 
     def test_shard_map_counts_all_shards(self, rules):
         from jax.sharding import PartitionSpec as P
-        body = compat.shard_map(lambda x: x @ x, mesh=rules.mesh,
-                                in_specs=P(None, None),
-                                out_specs=P(None, None), check_vma=False)
+        body = jax.shard_map(lambda x: x @ x, mesh=rules.mesh,
+                             in_specs=P(None, None),
+                             out_specs=P(None, None), check_vma=False)
         c = analytic_cost(body, _w(32, 32))["flops"]
         # 1-device mesh -> exactly one shard's flops
         assert c >= 2 * 32 * 32 * 32
@@ -97,9 +96,9 @@ class TestWalker:
         import warnings
 
         from jax.sharding import PartitionSpec as P
-        body = compat.shard_map(lambda x: x @ x, mesh=rules.mesh,
-                                in_specs=P(None, None),
-                                out_specs=P(None, None), check_vma=False)
+        body = jax.shard_map(lambda x: x @ x, mesh=rules.mesh,
+                             in_specs=P(None, None),
+                             out_specs=P(None, None), check_vma=False)
         with warnings.catch_warnings():
             warnings.simplefilter("error", RuntimeWarning)
             analytic_cost(body, _w(32, 32), strict=True)

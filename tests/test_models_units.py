@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs.base import MoEConfig
 from repro.kernels.ref import attention_reference
 from repro.models import attention as attn
@@ -129,7 +128,7 @@ class TestMoE:
         import numpy as np
         mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
                     ("data", "model"))
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             lambda *a: moe_block_local(*a, moe=moe, model_axis="model",
                                        data_axes=("data",)),
             mesh=mesh, in_specs=(P(), P(), P(), P(), P()),
