@@ -52,37 +52,30 @@ def check(ok: bool, what: str) -> None:
         raise SmokeFailure(what)
 
 
-class CompileClock:
-    """Seconds XLA spent compiling, and persistent-cache hits, from JAX's
-    own monitoring events."""
-
-    def __init__(self):
-        import jax
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-        elif event == "/jax/compilation_cache/cache_retrieval_time_sec":
-            self.cache_hits += 1
-
-
 def peak_hbm_gb() -> float:
     import jax
     stats = jax.devices()[0].memory_stats() or {}
     return stats.get("peak_bytes_in_use", 0) / 1e9
 
 
-def run_phase(name: str, clock: CompileClock, fn, *args) -> str:
+def compiles(rows: dict) -> tuple:
+    """(compile seconds, persistent-cache hits) of a ``SPANS`` table."""
+    return (sum(r.total_s for n, r in rows.items()
+                if n.startswith("compile.")),
+            sum(r.count for n, r in rows.items()
+                if n.startswith("compile_cached.")))
+
+
+def run_phase(name: str, fn, *args) -> str:
     """Run one phase; print its line (seconds, compile seconds, cache hits,
     peak HBM so far, what it checked)."""
-    c0, h0, t0 = clock.compile_s, clock.cache_hits, time.perf_counter()
+    from repro.core.profiler import SPANS
+    snap, t0 = SPANS.snapshot(), time.perf_counter()
     detail = fn(*args)
+    compile_s, hits = compiles(SPANS.since(snap))
     print(f"phase {name}: {time.perf_counter() - t0:.1f} s, compile "
-          f"{clock.compile_s - c0:.1f} s, {clock.cache_hits - h0} cache "
-          f"hits, peak HBM {peak_hbm_gb():.2f} GB; {detail}", flush=True)
+          f"{compile_s:.1f} s, {hits} cache hits, peak HBM "
+          f"{peak_hbm_gb():.2f} GB; {detail}", flush=True)
     return detail
 
 
@@ -179,10 +172,10 @@ def serve(workdir: Path, tiny: bool) -> str:
             f"{tokens} tokens in {s['serve_s']:.1f} s; {agree}")
 
 
-def one_chip(workdir: Path, clock: CompileClock, tiny: bool = False) -> None:
-    run_phase("a (cold train)", clock, train_cold, workdir / "train", tiny)
-    run_phase("b (warm train)", clock, train_warm, workdir / "train", tiny)
-    run_phase("c (serve)", clock, serve, workdir / "serve", tiny)
+def one_chip(workdir: Path, tiny: bool = False) -> None:
+    run_phase("a (cold train)", train_cold, workdir / "train", tiny)
+    run_phase("b (warm train)", train_warm, workdir / "train", tiny)
+    run_phase("c (serve)", serve, workdir / "serve", tiny)
 
 
 # ---------------------------------------------------------------------------
@@ -215,9 +208,9 @@ def sharded_train(workdir: Path, tiny: bool) -> str:
             f"{', '.join(f'{d}: {b} ({shares[d]:.4f})' for d, b in sorted(per_dev.items()))}")
 
 
-def four_chips(workdir: Path, clock: CompileClock, tiny: bool = False) -> None:
-    run_phase("4-chip (1x1 vs 2x2 train)", clock, sharded_train,
-              workdir / "mesh", tiny)
+def four_chips(workdir: Path, tiny: bool = False) -> None:
+    run_phase("4-chip (1x1 vs 2x2 train)", sharded_train, workdir / "mesh",
+              tiny)
 
 
 # ---------------------------------------------------------------------------
@@ -249,23 +242,24 @@ def main(argv=None) -> int:
     print(f"device: {device['count']} x {device['kind']} "
           f"({device['platform']})", flush=True)
 
+    from repro.core.profiler import SPANS
     from repro.launch.compile_cache import use_compile_cache
     cache = use_compile_cache()
     print(f"compile cache: {cache} ({cache_entries(cache)} entries before)",
           flush=True)
-    clock = CompileClock()
+    snap = SPANS.snapshot()
 
     shutil.rmtree(WORKDIR, ignore_errors=True)
     try:
-        (four_chips if args.four_chips else one_chip)(WORKDIR, clock)
+        (four_chips if args.four_chips else one_chip)(WORKDIR)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
     finally:
         shutil.rmtree(WORKDIR, ignore_errors=True)
+    compile_s, hits = compiles(SPANS.since(snap))
     print(f"compile cache: {cache} ({cache_entries(cache)} entries after); "
-          f"compile {clock.compile_s:.1f} s in all, {clock.cache_hits} "
-          f"cache hits", flush=True)
+          f"compile {compile_s:.1f} s in all, {hits} cache hits", flush=True)
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

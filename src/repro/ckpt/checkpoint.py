@@ -39,6 +39,7 @@ from repro.ckpt.index import TensorIndex
 from repro.ckpt.plan import (RestorePlan, build_restore_plan,
                              dim_slices_for_spec, execute_plan)
 from repro.core.pipeline import CRITICAL, DEFERRED
+from repro.core.profiler import current, span
 from repro.dfs.hdfs import HdfsCluster
 from repro.dfs.striped import StripedReader, StripedWriter
 
@@ -458,27 +459,35 @@ class Checkpointer:
         (DEFERRED — it only has to land before the first optimizer
         update), so a resume never convoys foreground startup I/O.
         """
-        index, plans = self.plan_restore(
-            step, *likes, specs=specs, rules=rules, axis_sizes=axis_sizes,
-            coords=coords, shard_slices=shard_slices, sched=sched,
-            **plan_kw)
-        reader = self._reader(step, sched=sched, priority=priority,
-                              index=index)
-        results = (self._execute_wave(reader, plans[0], priority=priority)
-                   if plans else {})
+        with span("ckpt.plan"):
+            index, plans = self.plan_restore(
+                step, *likes, specs=specs, rules=rules,
+                axis_sizes=axis_sizes, coords=coords,
+                shard_slices=shard_slices, sched=sched, **plan_kw)
+            reader = self._reader(step, sched=sched, priority=priority,
+                                  index=index)
+        with span("ckpt.wave.params"):
+            results = (self._execute_wave(reader, plans[0],
+                                          priority=priority)
+                       if plans else {})
         if not async_tail:
-            for plan in plans[1:]:
-                results.update(self._execute_wave(reader, plan,
-                                                  priority=priority))
-            return tuple(self._assemble(likes, 0, results))
-        first = self._assemble(likes[:1], 0, results)[0]
+            with span("ckpt.wave.opt"):
+                for plan in plans[1:]:
+                    results.update(self._execute_wave(reader, plan,
+                                                      priority=priority))
+            with span("ckpt.assemble"):
+                return tuple(self._assemble(likes, 0, results))
+        with span("ckpt.assemble"):
+            first = self._assemble(likes[:1], 0, results)[0]
+        parent = current()
 
         def _tail():
-            res = {}
-            for plan in plans[1:]:
-                res.update(self._execute_wave(reader, plan,
-                                              priority=tail_priority))
-            return tuple(self._assemble(likes[1:], 1, res))
+            with span("ckpt.wave.opt", parent=parent):
+                res = {}
+                for plan in plans[1:]:
+                    res.update(self._execute_wave(reader, plan,
+                                                  priority=tail_priority))
+                return tuple(self._assemble(likes[1:], 1, res))
 
         if len(likes) <= 1:
             fut: Future = Future()

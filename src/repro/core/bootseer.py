@@ -40,7 +40,7 @@ from repro.blockstore.registry import Registry
 from repro.blockstore.swarm import Swarm, Topology
 from repro.core.pipeline import (CRITICAL, DEFERRED, IOScheduler, TaskSpec,
                                  attribution, gating_counts, run_node_dags)
-from repro.core.profiler import StageAnalysisService, StageLogger
+from repro.core.profiler import StageAnalysisService, StageLogger, count
 from repro.core.stages import Stage, StartupTask
 from repro.dfs.fuse import HdfsFuseMount
 from repro.dfs.hdfs import HdfsCluster
@@ -375,6 +375,7 @@ class BootseerRuntime:
             if restored is None and spec.env_setup is not None:
                 before = snapshot_dir(target)
                 spec.env_setup(target, rank)
+                count(f"startup.{StartupTask.ENV_INSTALL}.ran")
                 if self.optimize and rank == 0:
                     # record-phase fence: rank 0 snapshots its own install.
                     # The launch profile (LD_PRELOAD, XLA_FLAGS, dtype

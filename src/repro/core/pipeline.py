@@ -45,6 +45,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
+from repro.core.profiler import current, span
 from repro.core.stages import Stage
 
 # ----------------------------------------------------------------------
@@ -268,6 +269,8 @@ class _NodeRun:
         self.tasks = {t.name: t for t in tasks}
         self.logger = logger
         self.clock = clock
+        # tasks run on pool threads: their spans take the caller's span
+        self.parent = current()
         self.result = NodeDagResult()
         self.done: set = set()
         self.launched: set = set()
@@ -303,7 +306,8 @@ class _NodeRun:
             self._stage_begun.add(t.stage)
             self.logger.begin(t.stage, ts=rec.start)
         deps_out = {d: self.result.values.get(d) for d in t.deps}
-        value = t.fn(deps_out)
+        with span(f"startup.{t.name}", parent=self.parent):
+            value = t.fn(deps_out)
         rec.end = self.clock()
         self.result.records[t.name] = rec
         self.result.values[t.name] = value
@@ -330,7 +334,13 @@ class _NodeRun:
             if all(d in self.done for d in t.deps):
                 deps_out = {d: self.result.values.get(d) for d in t.deps}
                 self.result.deferred.append(
-                    (t.name, lambda t=t, deps_out=deps_out: t.fn(deps_out)))
+                    (t.name, lambda t=t, deps_out=deps_out:
+                     _run_deferred(t, deps_out, self.parent)))
+
+
+def _run_deferred(t: TaskSpec, deps_out: dict, parent):
+    with span(f"startup.{t.name}", parent=parent):
+        return t.fn(deps_out)
 
 
 def run_node_dags(node_tasks: Sequence[Sequence[TaskSpec]], *,

@@ -4,6 +4,14 @@ Worker nodes emit stage-transition log lines ("print/echo instrumentation");
 a per-node LogParser extracts StageEvents; the central StageAnalysisService
 groups them into per-node and per-job stage durations, which power both the
 §3 characterization and the §5 evaluation.
+
+In-process spans: :func:`span` marks a stretch of the program both in a
+``jax.profiler`` trace (as ``repro.<name>``, on the clock of the device
+operations) and in the process-wide :data:`SPANS` totals (count, total
+and self seconds per name); :func:`count` adds to a counter in the same
+store, and :func:`watch_compiles` records every XLA compile there as
+``compile.<fun_name>``.  Recording is always on: a span costs a few
+microseconds and never syncs with the device.
 """
 
 from __future__ import annotations
@@ -12,13 +20,164 @@ import io
 import json
 import re
 import statistics
+import threading
 import time
 from collections import defaultdict
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from pathlib import Path
-from typing import Iterable, Optional, TextIO
+from typing import Iterable, NamedTuple, Optional, TextIO
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.stages import GPU_CONSUMING, STAGE_ORDER, Stage
+
+SPAN_PREFIX = "repro."
+
+
+class SpanRow(NamedTuple):
+    """Totals of one span or counter name.  ``self_s`` leaves out the
+    time of spans nested in it on the same thread; ``parent`` names the
+    enclosing span of the latest call (None at the top)."""
+    count: int
+    total_s: float
+    self_s: float
+    parent: Optional[str]
+
+
+class SpanTotals:
+    """Process-wide totals of spans and counters, by name."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._rows: dict = {}
+
+    def add(self, name: str, n: int = 1, seconds: float = 0.0,
+            self_s: float = 0.0, parent: Optional[str] = None) -> None:
+        with self._lock:
+            c, t, s, _ = self._rows.get(name, (0, 0.0, 0.0, None))
+            self._rows[name] = SpanRow(c + n, t + seconds, s + self_s,
+                                       parent)
+
+    def snapshot(self) -> dict:
+        """{name: SpanRow} as they stand now."""
+        with self._lock:
+            return dict(self._rows)
+
+    def since(self, snap: dict) -> dict:
+        """{name: SpanRow} of what was recorded after ``snap`` was taken
+        (names with no new call are left out)."""
+        out = {}
+        for name, row in self.snapshot().items():
+            c, t, s, _ = snap.get(name, (0, 0.0, 0.0, None))
+            if row.count != c:
+                out[name] = SpanRow(row.count - c, row.total_s - t,
+                                    row.self_s - s, row.parent)
+        return out
+
+
+SPANS = SpanTotals()
+_local = threading.local()
+
+
+def current():
+    """The innermost open span on this thread (None outside any): pass it
+    as ``parent`` to a span of work submitted to another thread."""
+    return getattr(_local, "top", None)
+
+
+class span:
+    """``with span(name):`` records the block as ``repro.<name>`` in a
+    profiler trace and in :data:`SPANS`.  Its parent is the enclosing
+    span on this thread, or ``parent`` (see :func:`current`) for work
+    that runs on a pool thread."""
+
+    __slots__ = ("name", "parent", "_prev", "_tid", "_t0", "_child_s",
+                 "_ann")
+
+    def __init__(self, name: str, parent=None):
+        self.name = name
+        self.parent = parent
+
+    def __enter__(self):
+        self._prev = current()
+        if self.parent is None:
+            self.parent = self._prev
+        _local.top = self
+        self._tid = threading.get_ident()
+        self._child_s = 0.0
+        self._ann = TraceAnnotation(SPAN_PREFIX + self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        _local.top = self._prev
+        _close(self.name, dt, dt - self._child_s, self.parent, self._tid)
+
+
+def _close(name, seconds, self_s, parent, tid) -> None:
+    if parent is not None and parent._tid == tid:
+        parent._child_s += seconds
+    SPANS.add(name, 1, seconds, self_s,
+              None if parent is None else parent.name)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the counter ``name`` in :data:`SPANS`."""
+    top = current()
+    SPANS.add(name, n, parent=None if top is None else top.name)
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_watching = False
+_watch_lock = threading.Lock()
+
+
+def _on_compile_event(event: str, duration: float, **kw) -> None:
+    if event == _CACHE_READ_EVENT:
+        _local.cache_read = True
+    elif event == _COMPILE_EVENT:
+        fun = kw.get("fun_name", "unknown")
+        cached = getattr(_local, "cache_read", False)
+        _local.cache_read = False
+        top = current()
+        # a compile is a finished child of the span it ran in
+        _close(f"compile.{fun}", duration, duration, top,
+               threading.get_ident())
+        if cached:
+            count(f"compile_cached.{fun}")
+
+
+def watch_compiles() -> None:
+    """Record every XLA compile in :data:`SPANS` as ``compile.<fun_name>``
+    (count, seconds), and ``compile_cached.<fun_name>`` where the
+    persistent cache supplied the executable.  Idempotent; entry points
+    call it (through ``launch.compile_cache.use_compile_cache``)."""
+    global _watching
+    with _watch_lock:
+        if not _watching:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_event)
+            _watching = True
+
+
+def uncached_compiles(rows: dict) -> dict:
+    """{fun_name: programs compiled anew} from a ``SPANS.since`` table:
+    ``compile.*`` counts less ``compile_cached.*`` counts."""
+    out = {}
+    for name, row in rows.items():
+        if name.startswith("compile."):
+            fun = name[len("compile."):]
+            cached = rows.get(f"compile_cached.{fun}")
+            n = row.count - (cached.count if cached else 0)
+            if n:
+                out[fun] = n
+    return out
+
 
 _LINE = "BOOTSEER_STAGE ts={ts:.6f} job={job} node={node} stage={stage} ev={ev}\n"
 _RE = re.compile(
@@ -60,12 +219,16 @@ class StageLogger:
     class _Ctx:
         def __init__(self, logger, stage):
             self.logger, self.stage = logger, stage
+            name = stage.value if isinstance(stage, Stage) else str(stage)
+            self.span = span(f"stage.{name}")
 
         def __enter__(self):
+            self.span.__enter__()
             self.logger.begin(self.stage)
 
         def __exit__(self, *exc):
             self.logger.end(self.stage)
+            self.span.__exit__(*exc)
 
     def stage(self, stage: Stage | str) -> "_Ctx":
         return self._Ctx(self, stage)
@@ -142,21 +305,6 @@ class StageAnalysisService:
                  and span[1] is not None}
             if d:
                 out[node] = d
-        return out
-
-    def task_overlap_s(self, job: str) -> dict[str, float]:
-        """Per node: total pairwise overlap seconds between task spans —
-        > 0 proves stages actually ran concurrently (the pipelined-DAG
-        regression metric that replaces brittle wall-clock ratios on
-        GIL-convoy-prone 2-CPU runners)."""
-        out = {}
-        for node, spans in self.task_spans(job).items():
-            xs = sorted(spans.values())
-            total = 0.0
-            for i, (b1, e1) in enumerate(xs):
-                for b2, e2 in xs[i + 1:]:
-                    total += max(0.0, min(e1, e2) - max(b1, b2))
-            out[node] = total
         return out
 
     def job_level_overhead(self, job: str) -> float:

@@ -1,8 +1,9 @@
 """Placement of JAX's persistent compilation cache.
 
 Only entry points call :func:`use_compile_cache` (the launch drivers'
-``main()`` and ``chip_smoke.py``), before their first compile and never at
-import: library code and tests leave JAX's cache configuration alone.
+``main()``, ``chip_smoke.py`` and the benchmark's ``run.py``), before their
+first compile and never at import: library code and tests leave JAX's
+cache configuration and its monitoring listeners alone.
 """
 
 from __future__ import annotations
@@ -11,6 +12,8 @@ import os
 from pathlib import Path
 
 import jax
+
+from repro.core.profiler import watch_compiles
 
 # a fixed path: the directory is part of each entry's key, so a cache that
 # moves with the caller's working directory would never hit
@@ -22,7 +25,9 @@ def use_compile_cache() -> str:
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already taken it
     and nothing else is set.  Otherwise the cache lives in ``.jax_cache/``
-    at the repository root."""
+    at the repository root.  Either way every compile from here on is
+    counted by program in ``profiler.SPANS`` (``watch_compiles``)."""
+    watch_compiles()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

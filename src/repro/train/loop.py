@@ -3,12 +3,11 @@ checkpointing through the striped DFS (repro.ckpt)."""
 
 from __future__ import annotations
 
-import time
 from typing import Callable, Optional
 
 import jax
-import numpy as np
 
+from repro.core.profiler import span
 from repro.models.model import Model
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.train.step import jit_train_step, state_shardings
@@ -85,42 +84,52 @@ def _train_loop(model: Model, *, batch: int, seq_len: int, steps: int,
             opt_state = jax.eval_shape(adamw_init, params)
         if restore_coords is None and restore_specs is not None:
             restore_coords = model.rules.coords_of_rank(0)
-        params, opt_tail = checkpointer.restore_planned(
-            resume_from, params, opt_state, specs=restore_specs,
-            rules=model.rules, coords=restore_coords, async_tail=True,
-            sched=restore_sched)
-        params = jax.device_put(params, pshard)
+        with span("train.restore"):
+            params, opt_tail = checkpointer.restore_planned(
+                resume_from, params, opt_state, specs=restore_specs,
+                rules=model.rules, coords=restore_coords, async_tail=True,
+                sched=restore_sched)
+        with span("train.place.params"):
+            params = jax.device_put(params, pshard)
         start_step = resume_from
 
-    step_fn = jit_train_step(model, opt_cfg, batch)
-    loader = ShardedLoader(SyntheticStream(model.cfg.vocab_size, seed),
-                           model.rules, batch, seq_len)
+    with span("train.build"):
+        step_fn = jit_train_step(model, opt_cfg, batch)
+        loader = ShardedLoader(SyntheticStream(model.cfg.vocab_size, seed),
+                               model.rules, batch, seq_len)
     if opt_tail is not None and steps > 0:
         # realize the overlap: jit is lazy, so drive the real compile with
         # a discarded warmup step on a zero optimizer state (same shapes,
         # dtypes and shardings, so the cache hit carries over) while the
         # optimizer wave streams in the background.  The step donates its
         # arguments, so warm up on a copy of the params.
-        step_fn(jax.tree.map(jax.numpy.copy, params), adamw_init(params),
-                loader(start_step))
+        with span("train.warmup.copy"):
+            warm_params = jax.tree.map(jax.numpy.copy, params)
+        with span("train.warmup.zeros"):
+            warm_opt = adamw_init(params)
+        with span("train.warmup.step"):
+            step_fn(warm_params, warm_opt, loader(start_step))
     if opt_tail is not None:
-        (opt_state,) = opt_tail.result()
-        opt_state = jax.device_put(opt_state, oshard)
+        with span("train.opt_wait"):
+            (opt_state,) = opt_tail.result()
+        with span("train.place.opt"):
+            opt_state = jax.device_put(opt_state, oshard)
 
     history = []
     saves = 0       # saves this run: every full_every-th one is full
     last_saved: Optional[int] = None
-    t0 = time.perf_counter()
     for step in range(start_step, start_step + steps):
-        data = loader(step)
-        params, opt_state, metrics = step_fn(params, opt_state, data)
-        if (step - start_step) % log_every == 0 or step == start_step + steps - 1:
-            loss = float(metrics["loss"])
-            history.append({"step": step, "loss": loss,
-                            "grad_norm": float(metrics["grad_norm"]),
-                            "t": time.perf_counter() - t0})
-            log_fn(f"step {step:5d}  loss {loss:.4f}  "
-                   f"gnorm {float(metrics['grad_norm']):.3f}")
+        with span("train.step"):
+            data = loader(step)
+            params, opt_state, metrics = step_fn(params, opt_state, data)
+            if (step - start_step) % log_every == 0 \
+                    or step == start_step + steps - 1:
+                loss = float(metrics["loss"])
+                grad_norm = float(metrics["grad_norm"])
+                history.append({"step": step, "loss": loss,
+                                "grad_norm": grad_norm})
+                log_fn(f"step {step:5d}  loss {loss:.4f}  "
+                       f"gnorm {grad_norm:.3f}")
         if checkpointer is not None and ckpt_every and \
                 (step + 1) % ckpt_every == 0:
             if full_every and saves % full_every != 0 \

@@ -11,6 +11,7 @@ from repro.blockstore.image import build_image
 from repro.blockstore.registry import Registry
 from repro.ckpt.checkpoint import Checkpointer
 from repro.core.bootseer import BootseerRuntime, JobSpec
+from repro.core.profiler import SPANS
 from repro.core.stages import Stage
 from repro.dfs.hdfs import HdfsCluster, ThrottleModel
 from repro.dfs.striped import StripeMissingError
@@ -56,14 +57,23 @@ def test_baseline_vs_bootseer_startup(env, tmp_path):
     makes elapsed-time comparisons flaky (see the slow-marked
     test_warm_restart_beats_baseline_walltime for the wall-clock form)."""
     _, reg, hdfs, ck = env
+    installs = []
+
+    def startup(rt):
+        snap = SPANS.snapshot()
+        res = rt.run_startup(_spec(), checkpointer=ck)
+        ran = SPANS.since(snap).get("startup.env.install.ran")
+        installs.append(ran.count if ran else 0)
+        return res
+
     base_rt = BootseerRuntime(registry=reg, hdfs=hdfs,
                               workdir=tmp_path / "wb", optimize=False)
-    rb = base_rt.run_startup(_spec(), checkpointer=ck)
+    rb = startup(base_rt)
 
     opt_rt = BootseerRuntime(registry=reg, hdfs=hdfs,
                              workdir=tmp_path / "wo", optimize=True)
-    r1 = opt_rt.run_startup(_spec(), checkpointer=ck)   # record run
-    r2 = opt_rt.run_startup(_spec(), checkpointer=ck)   # warm restart
+    r1 = startup(opt_rt)   # record run
+    r2 = startup(opt_rt)   # warm restart
 
     # the record run must NOT claim it prefetched (it created the record;
     # regression test for the note once re-querying has_record after the
@@ -79,10 +89,9 @@ def test_baseline_vs_bootseer_startup(env, tmp_path):
     assert opt_rt.env_cache.stats["local_cache_hits"] == 2
 
     # install ran on every baseline/record node, on NO warm node: the
-    # env.install task degenerates to the restored-cache check
-    for attr in r2.notes["critical_path"].values():
-        tasks = attr["tasks"]
-        assert tasks["env.install"]["s"] < tasks["env.restore"]["s"] + 0.08
+    # env.install task degenerates to the restored-cache check (counted
+    # where the install commands run, not timed)
+    assert installs == [3, 3, 0]
 
     # scheduler-counted I/O: critical-path DFS bytes flowed (env archive
     # windows + params-wave preads), and the warm restart added ZERO
