@@ -21,6 +21,11 @@ restore composes the base + delta chain into one layered reader so a
 resume reads each logical range exactly once from the newest layer that
 holds it.  The planner, waves and ``pread_many`` batching are identical
 for full and delta steps.
+
+Read-once hand-off (repro.ckpt.handoff): the startup DAG's checkpoint
+waves stage the bytes they read in ``Checkpointer.handoff``, and
+``restore_planned`` serves the step from there, reading from the DFS
+only what no staged piece holds.
 """
 
 from __future__ import annotations
@@ -35,6 +40,7 @@ import numpy as np
 
 from repro.ckpt.delta import (DEFAULT_DIFF_CHUNK, LayeredReader,
                               build_layer_map, changed_ranges, chunk_crcs)
+from repro.ckpt.handoff import HandoffReader, HandoffStore
 from repro.ckpt.index import TensorIndex
 from repro.ckpt.plan import (RestorePlan, build_restore_plan,
                              dim_slices_for_spec, execute_plan)
@@ -137,6 +143,9 @@ class Checkpointer:
         # granularity of the save_delta CRC diff; every full save records
         # per-tensor chunk hashes at this size so it can serve as a base
         self.diff_chunk = diff_chunk
+        # bytes of one resume step that the startup DAG read, handed to
+        # the restore that follows it so they cross the DFS once
+        self.handoff = HandoffStore()
 
     # ----- paths -----
 
@@ -216,6 +225,7 @@ class Checkpointer:
             self.hdfs.write(path, b"".join(blobs))
 
     def save(self, step: int, *trees: Any, meta: Optional[dict] = None) -> TensorIndex:
+        self.handoff.drop(step=step)
         index, payloads = self._index_trees(step, trees, meta)
         self._write_stream(self.data_path(step), payloads)
         self.hdfs.write(self.index_path(step), index.to_json().encode())
@@ -237,6 +247,7 @@ class Checkpointer:
                 raise ValueError(
                     "save_delta: no base snapshot to diff against — write "
                     "a full save() first")
+        self.handoff.drop(step=step)
         base_index = self.load_index(base)
         if base_index.hash_chunk is None:
             raise ValueError(
@@ -458,36 +469,50 @@ class Checkpointer:
         model init) and the async optimizer tail at ``tail_priority``
         (DEFERRED — it only has to land before the first optimizer
         update), so a resume never convoys foreground startup I/O.
+
+        Bytes of ``step`` staged in :attr:`handoff` by the startup DAG
+        (under the same manifest) are served from memory, or waited for
+        while in flight; only what no staged piece holds is read from
+        the DFS.  The restore drops the staging when it ends.
         """
         with span("ckpt.plan"):
             index, plans = self.plan_restore(
                 step, *likes, specs=specs, rules=rules,
                 axis_sizes=axis_sizes, coords=coords,
                 shard_slices=shard_slices, sched=sched, **plan_kw)
-            reader = self._reader(step, sched=sched, priority=priority,
-                                  index=index)
-        with span("ckpt.wave.params"):
-            results = (self._execute_wave(reader, plans[0],
-                                          priority=priority)
-                       if plans else {})
-        if not async_tail:
-            with span("ckpt.wave.opt"):
-                for plan in plans[1:]:
-                    results.update(self._execute_wave(reader, plan,
-                                                      priority=priority))
+            staging = self.handoff.lookup(step, index)
+            reader = HandoffReader(
+                self._reader(step, sched=sched, priority=priority,
+                             index=index), staging)
+        try:
+            with span("ckpt.wave.params"):
+                results = (self._execute_wave(reader, plans[0],
+                                              priority=priority)
+                           if plans else {})
+            if not async_tail:
+                with span("ckpt.wave.opt"):
+                    for plan in plans[1:]:
+                        results.update(self._execute_wave(
+                            reader, plan, priority=priority))
+                with span("ckpt.assemble"):
+                    return tuple(self._assemble(likes, 0, results))
             with span("ckpt.assemble"):
-                return tuple(self._assemble(likes, 0, results))
-        with span("ckpt.assemble"):
-            first = self._assemble(likes[:1], 0, results)[0]
+                first = self._assemble(likes[:1], 0, results)[0]
+        finally:
+            if not (async_tail and len(likes) > 1):
+                self.handoff.drop(staging=staging)
         parent = current()
 
         def _tail():
-            with span("ckpt.wave.opt", parent=parent):
-                res = {}
-                for plan in plans[1:]:
-                    res.update(self._execute_wave(reader, plan,
-                                                  priority=tail_priority))
-                return tuple(self._assemble(likes[1:], 1, res))
+            try:
+                with span("ckpt.wave.opt", parent=parent):
+                    res = {}
+                    for plan in plans[1:]:
+                        res.update(self._execute_wave(
+                            reader, plan, priority=tail_priority))
+                    return tuple(self._assemble(likes[1:], 1, res))
+            finally:
+                self.handoff.drop(staging=staging)
 
         if len(likes) <= 1:
             fut: Future = Future()

@@ -21,6 +21,7 @@ the JSON format stays readable by and from older checkpoints.
 
 from __future__ import annotations
 
+import hashlib
 import json
 from dataclasses import dataclass
 from typing import Iterable, Optional
@@ -53,6 +54,9 @@ class TensorIndex:
         self.hash_chunk: Optional[int] = None
         self.chunk_hashes: dict[str, list[int]] = {}
         self.delta: Optional[dict] = None
+        # digest of the manifest text it was read from (None if built in
+        # memory): what a staged hand-off of the step is checked against
+        self.digest: Optional[str] = None
 
     @property
     def is_delta(self) -> bool:
@@ -127,4 +131,5 @@ class TensorIndex:
             delta = dict(delta,
                          ranges=[tuple(r) for r in delta.get("ranges", [])])
         idx.delta = delta
+        idx.digest = hashlib.blake2b(raw.encode(), digest_size=16).hexdigest()
         return idx

@@ -284,17 +284,43 @@ def execute_plan(reader, plan: RestorePlan, *,
 
     Contiguous ops read zero-copy straight into the preallocated per-tensor
     buffers; gap-coalesced multi-segment ops go through one scratch buffer
-    and scatter out (bounded by the plan's ``max_waste``).
+    and scatter out (bounded by the plan's ``max_waste``).  A reader with a
+    ``staging`` (repro.ckpt.handoff) holds bytes in memory already: each
+    op's segments are asked for one by one through ``take``, a whole tensor
+    comes back as the reader's own buffer with no copy, and the other
+    segments read straight into their tensor's buffer.
     """
-    bufs = [np.empty(t.nbytes, np.uint8) for t in plan.tensors]
+    take = reader.take if getattr(reader, "staging", None) is not None \
+        else None
+    bufs: list = [None] * len(plan.tensors)
+
+    def dest(i: int) -> np.ndarray:
+        if bufs[i] is None:
+            bufs[i] = np.empty(plan.tensors[i].nbytes, np.uint8)
+        return bufs[i]
+
     ranges: list[tuple] = []
     into: list = []
     scatter: list[tuple] = []
     for op in plan.reads:
+        if take is not None:
+            for s in op.segments:
+                t = plan.tensors[s.tensor]
+                got = take(op.offset + s.src_off, s.length) \
+                    if s.length == t.nbytes else None
+                if got is None:
+                    ranges.append((op.offset + s.src_off, s.length))
+                    into.append(dest(s.tensor)[s.dest_off:
+                                               s.dest_off + s.length])
+                elif got.ctypes.data % np.dtype(t.dtype).itemsize:
+                    bufs[s.tensor] = got.copy()     # unaligned in memory
+                else:
+                    bufs[s.tensor] = got
+            continue
         ranges.append((op.offset, op.length))
         if op.contiguous:
             s = op.segments[0]
-            into.append(bufs[s.tensor][s.dest_off:s.dest_off + s.length])
+            into.append(dest(s.tensor)[s.dest_off:s.dest_off + s.length])
         else:
             scratch = np.empty(op.length, np.uint8)
             into.append(scratch)
@@ -303,7 +329,7 @@ def execute_plan(reader, plan: RestorePlan, *,
         _checked_pread_many(reader, ranges, into, priority=priority)
     for op, scratch in scatter:
         for s in op.segments:
-            bufs[s.tensor][s.dest_off:s.dest_off + s.length] = \
+            dest(s.tensor)[s.dest_off:s.dest_off + s.length] = \
                 scratch[s.src_off:s.src_off + s.length]
     out = []
     for t, buf in zip(plan.tensors, bufs):
@@ -316,7 +342,7 @@ def execute_plan(reader, plan: RestorePlan, *,
 
 def read_plan(reader, plan: RestorePlan, *,
               batch_bytes: int = 4 * DEFAULT_MAX_READ,
-              priority: Optional[int] = None) -> int:
+              priority: Optional[int] = None, sink=None) -> int:
     """Execute only the I/O of a plan (no tensor materialization) — the
     startup-critical resume read in the BootSeer runtime.  Ops are issued
     in batches whose throwaway buffers total at most ``batch_bytes``, so N
@@ -328,7 +354,11 @@ def read_plan(reader, plan: RestorePlan, *,
     that had to reconstruct a lost stripe from parity mid-plan, the extra
     source bytes of the degraded read (``reconstruction_read_bytes``
     delta), so callers report the I/O that actually hit the DFS rather
-    than the healthy-path plan size."""
+    than the healthy-path plan size.
+
+    With ``sink`` (a ``repro.ckpt.handoff.Sink``) the bytes land in the
+    sink's staging instead of throwaway buffers, kept for the restore that
+    follows the startup DAG."""
     stats = getattr(reader, "stats", None)
     recon0 = stats.get("reconstruction_read_bytes", 0) if stats else 0
     ops = plan.reads
@@ -338,11 +368,14 @@ def read_plan(reader, plan: RestorePlan, *,
         while j < len(ops) and (j == i or acc + ops[j].length <= batch_bytes):
             acc += ops[j].length
             j += 1
-        _checked_pread_many(reader,
-                            [(op.offset, op.length) for op in ops[i:j]],
-                            [np.empty(op.length, np.uint8)
-                             for op in ops[i:j]],
-                            priority=priority)
+        ranges = [(op.offset, op.length) for op in ops[i:j]]
+        if sink is None:
+            bufs = [np.empty(ln, np.uint8) for _, ln in ranges]
+        else:
+            ranges, bufs = sink.into(ranges)
+        _checked_pread_many(reader, ranges, bufs, priority=priority)
+        if sink is not None:
+            sink.landed(ranges)
         i = j
     extra = (stats.get("reconstruction_read_bytes", 0) - recon0) \
         if stats else 0

@@ -207,6 +207,13 @@ class BootseerRuntime:
         if errors:
             raise errors[0]
 
+    @staticmethod
+    def _drop_staging(checkpointer, job_tag: str) -> None:
+        """Drop the hand-off a run staged in ``checkpointer``: its pending
+        pieces fail, so a restore reads those ranges from the DFS."""
+        if checkpointer is not None:
+            checkpointer.handoff.drop(owner=job_tag)
+
     def region_replicator(self, **kwargs) -> RegionReplicator:
         """A :class:`~repro.fabric.federation.RegionReplicator` bound to
         this runtime's swarm and hot-block service.  Register each
@@ -402,10 +409,12 @@ class BootseerRuntime:
             # optimizer on, the reads first consult the node's fabric
             # cache for ranges staged by restore-ahead prefetch — a warm
             # crash-restart replays the params wave from node-local disk
+            # — and the bytes read are staged in the checkpointer's
+            # hand-off, so the loop's planned restore reads them once
             if spec.resume_step is None or checkpointer is None:
                 return None
             from repro.ckpt.plan import read_plan
-            reader, plans = _restore_plans(
+            index, reader, plans = _restore_plans(
                 checkpointer, spec.resume_step, rank=rank, nodes=n,
                 resume_plan=spec.resume_plan, sched=self.io_sched,
                 cache=(self._node_cache(spec.job_id, rank)
@@ -414,23 +423,33 @@ class BootseerRuntime:
                     restore_ahead_hit_bytes=nb))
             if not plans:
                 return None
-            read_plan(reader, plans[0], priority=CRITICAL)
             if not self.optimize:
-                # baseline: both waves block model init, as the paper's
-                # unoptimized runtime does
+                # baseline: both waves block model init and keep
+                # nothing, as the paper's unoptimized runtime does
+                read_plan(reader, plans[0], priority=CRITICAL)
                 for p in plans[1:]:
                     read_plan(reader, p)
                 return None
-            return (reader, plans[1:])
+            sink = checkpointer.handoff.stage(
+                spec.resume_step, index, owner=job_tag).sink()
+            read_plan(reader, plans[0], priority=CRITICAL, sink=sink)
+            # from here on a restore waits for the deferred wave's
+            # ranges instead of reading them a second time
+            sink.expect([(op.offset, op.length)
+                         for p in plans[1:] for op in p.reads])
+            return (reader, plans[1:], sink)
 
         def ckpt_opt(deps):
             handle = deps[StartupTask.CKPT_PARAMS_WAVE]
             if not handle:
                 return 0
             from repro.ckpt.plan import read_plan
-            reader, tail = handle
-            return sum(read_plan(reader, p, priority=DEFERRED)
-                       for p in tail)
+            reader, tail, sink = handle
+            try:
+                return sum(read_plan(reader, p, priority=DEFERRED, sink=sink)
+                           for p in tail)
+            finally:
+                sink.abandon()
 
         tasks.append(TaskSpec(StartupTask.CKPT_PARAMS_WAVE, ckpt_params,
                               stage=Stage.MODEL_INIT))
@@ -508,8 +527,14 @@ class BootseerRuntime:
             # timestamps read directly as "seconds into this startup"
             return time.perf_counter() - t_zero
 
-        results = run_node_dags(node_tasks, pipelined=pipelined,
-                                loggers=loggers, clock=clock)
+        try:
+            results = run_node_dags(node_tasks, pipelined=pipelined,
+                                    loggers=loggers, clock=clock)
+        except BaseException:
+            # the deferred waves that would fill this run's pending
+            # hand-off pieces never run
+            self._drop_staging(checkpointer, job_tag)
+            raise
         # the ONE remaining cross-node sync: every node's gating chains
         # are done, so TRAINING begins everywhere at the same instant
         total = clock()
@@ -527,6 +552,8 @@ class BootseerRuntime:
                 prefetch_val["client"].release_pins()
             for _name, thunk in res.deferred:
                 fut = self._submit_deferred(thunk)
+                if fut is None:
+                    self._drop_staging(checkpointer, job_tag)
                 if _name == StartupTask.TUNE_RESTORE:
                     tune_future = fut
 
@@ -651,7 +678,7 @@ class BootseerRuntime:
             def thunk():
                 cache = self._node_cache(spec.job_id, rank)
                 cache.unpin_job(tag)
-                reader, plans = _restore_plans(
+                _, reader, plans = _restore_plans(
                     checkpointer, step, rank=rank, nodes=n,
                     resume_plan=spec.resume_plan, sched=self.io_sched)
                 if not plans:
@@ -684,7 +711,7 @@ def _ckpt_stream(checkpointer, step: int) -> str:
 def _restore_plans(checkpointer, step: int, *, rank: int, nodes: int,
                    resume_plan: Any = "full", sched=None, cache=None,
                    on_hit=None):
-    """Resolve ``resume_plan`` into (reader, per-wave RestorePlans).
+    """Resolve ``resume_plan`` into (index, reader, per-wave RestorePlans).
 
     With ``cache`` (a fabric ``NodeCache``), the reader consults
     range-addressed entries staged by restore-ahead prefetch before
@@ -708,7 +735,7 @@ def _restore_plans(checkpointer, step: int, *, rank: int, nodes: int,
         eff_nodes = nodes if resume_plan == "rows" else 1
         plans = [plan_for_rank(index, rank, eff_nodes, names=names)
                  for names in index.wave_names()]
-    return reader, plans
+    return index, reader, plans
 
 
 def planned_restore_bytes(checkpointer, step: int, *, rank: int, nodes: int,
@@ -728,9 +755,9 @@ def planned_restore_bytes(checkpointer, step: int, *, rank: int, nodes: int,
     """
     from repro.ckpt.plan import read_plan
 
-    reader, plans = _restore_plans(checkpointer, step, rank=rank,
-                                   nodes=nodes, resume_plan=resume_plan,
-                                   sched=sched)
+    _, reader, plans = _restore_plans(checkpointer, step, rank=rank,
+                                      nodes=nodes, resume_plan=resume_plan,
+                                      sched=sched)
     if not plans:
         return 0
     n = read_plan(reader, plans[0], priority=CRITICAL)

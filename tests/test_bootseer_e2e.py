@@ -168,6 +168,66 @@ def test_deferred_opt_wave_failure_surfaces(env, tmp_path):
         rt.drain_deferred()
 
 
+def test_warm_restart_hands_dag_bytes_to_the_loop(env, tmp_path, rules):
+    """run_startup then train_loop(resume_from=...): the loop's planned
+    restore takes every byte from the startup DAG's staged waves (the
+    optimizer wave possibly still in flight) and reads nothing from the
+    DFS but its manifest."""
+    import jax
+    from repro.configs import get_tiny
+    from repro.core.pipeline import IOScheduler
+    from repro.models.model import Model
+    from repro.optim.adamw import adamw_init
+    from repro.train.loop import train_loop
+    _, reg, hdfs, ck = env
+    model = Model(get_tiny("mamba2-370m"), rules)
+    params = model.init(jax.random.key(0))
+    ck.save(300, params, adamw_init(params))
+    total = ck.load_index(300).total_bytes
+    manifest = len(hdfs.read(ck.index_path(300)))
+
+    rt = BootseerRuntime(registry=reg, hdfs=hdfs, workdir=tmp_path / "w",
+                         optimize=True)
+    spec = JobSpec(**{**_spec().__dict__, "resume_step": 300})
+    rt.run_startup(spec, checkpointer=ck)
+    snap = SPANS.snapshot()
+    sched = IOScheduler()
+    warm, _, hist = train_loop(model, batch=2, seq_len=16, steps=1,
+                               log_fn=lambda *_: None, checkpointer=ck,
+                               resume_from=300, restore_sched=sched)
+    rows = SPANS.since(snap)
+    rt.drain_deferred()
+    rt.close()
+    assert rows["ckpt.handoff.hit_bytes"].count == total
+    assert "ckpt.handoff.miss_bytes" not in rows
+    assert sum(sched.snapshot()["dfs"]["bytes"].values()) == manifest
+    # the same step from a cold restore (nothing staged) trains the same
+    cold, _, cold_hist = train_loop(
+        model, batch=2, seq_len=16, steps=1, log_fn=lambda *_: None,
+        checkpointer=Checkpointer(hdfs, striped=True, width=8),
+        resume_from=300)
+    assert hist == cold_hist
+    for a, b in zip(jax.tree.leaves(warm), jax.tree.leaves(cold)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_unoptimized_startup_stages_nothing(env, tmp_path):
+    _, reg, hdfs, ck = env
+    rt = BootseerRuntime(registry=reg, hdfs=hdfs, workdir=tmp_path / "w",
+                         optimize=False)
+    rt.run_startup(_spec(), checkpointer=ck)
+    rt.close()
+    assert ck.handoff.lookup(100, ck.load_index(100)) is None
+    snap = SPANS.snapshot()
+    (got,) = ck.restore_planned(
+        100, {"w": np.zeros((64, 4096), np.float32)})
+    rows = SPANS.since(snap)
+    assert "ckpt.handoff.hit_bytes" not in rows
+    assert rows["ckpt.handoff.miss_bytes"].count == 64 * 4096 * 4
+    np.testing.assert_array_equal(
+        got["w"], np.arange(64 * 4096, dtype=np.float32).reshape(64, -1))
+
+
 def test_hot_record_created_once(env, tmp_path):
     _, reg, hdfs, ck = env
     rt = BootseerRuntime(registry=reg, hdfs=hdfs, workdir=tmp_path / "w",
