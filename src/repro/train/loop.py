@@ -41,8 +41,8 @@ def _train_loop(model: Model, *, batch: int, seq_len: int, steps: int,
     ``resume_from``: checkpoint step to restore through the planner
     (``checkpointer.restore_planned``) before training.  Params restore
     first (wave 0); the optimizer state streams as an async second wave
-    that overlaps loader setup and the step-function's jit compilation
-    (driven eagerly by a discarded warmup step).  ``restore_specs``
+    that overlaps loader setup and the step's ahead-of-time compile.
+    ``restore_specs``
     optionally carries PartitionSpec trees congruent to (params, opt) for
     sharding-aware partial restore against ``model.rules``;
     ``restore_coords`` gives this host's mesh coordinates (default: mesh
@@ -97,18 +97,14 @@ def _train_loop(model: Model, *, batch: int, seq_len: int, steps: int,
         step_fn = jit_train_step(model, opt_cfg, batch)
         loader = ShardedLoader(SyntheticStream(model.cfg.vocab_size, seed),
                                model.rules, batch, seq_len)
-    if opt_tail is not None and steps > 0:
-        # realize the overlap: jit is lazy, so drive the real compile with
-        # a discarded warmup step on a zero optimizer state (same shapes,
-        # dtypes and shardings, so the cache hit carries over) while the
-        # optimizer wave streams in the background.  The step donates its
-        # arguments, so warm up on a copy of the params.
-        with span("train.warmup.copy"):
-            warm_params = jax.tree.map(jax.numpy.copy, params)
-        with span("train.warmup.zeros"):
-            warm_opt = adamw_init(params)
-        with span("train.warmup.step"):
-            step_fn(warm_params, warm_opt, loader(start_step))
+    with span("train.compile"):
+        # compile from shapes and shardings alone, so on a resume it runs
+        # while the optimizer wave streams in; every step, the first
+        # included, then calls this one executable
+        state = jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            (params, opt_state), (pshard, oshard))
+        step_fn = step_fn.lower(*state, loader(start_step)).compile()
     if opt_tail is not None:
         with span("train.opt_wait"):
             (opt_state,) = opt_tail.result()

@@ -154,6 +154,89 @@ def test_train_loop_resume_through_planner(hdfs, rules):
     assert jax.tree.structure(o2) == jax.tree.structure(opt)
 
 
+
+def _resumable_mamba2(hdfs, rules, step: int):
+    """A tiny mamba2 model and a step-``step`` checkpoint of a state one
+    train step in (so the AdamW moments are not zero).  Returns the model,
+    the checkpointer and the saved (params, opt) on the host."""
+    from repro.configs import get_tiny
+    from repro.models.model import Model
+    from repro.optim.adamw import AdamWConfig, adamw_init
+    from repro.train.step import jit_train_step
+    model = Model(get_tiny("mamba2-370m"), rules)
+    params = model.init(jax.random.key(0))
+    batch = {"tokens": jnp.ones((2, 16), jnp.int32),
+             "labels": jnp.ones((2, 16), jnp.int32)}
+    params, opt, _ = jit_train_step(model, AdamWConfig(), 2)(
+        params, adamw_init(params), batch)
+    saved = jax.tree.map(np.asarray, (params, opt))
+    ck = Checkpointer(hdfs, width=4)
+    ck.save(step, *saved)
+    return model, ck, saved
+
+
+def test_resumed_train_loop_matches_a_hand_run_step(hdfs, rules):
+    """A resumed train_loop, which compiles its step ahead of time, gives
+    bit for bit the params, optimizer state and losses of jit_train_step
+    run by hand on the restored state."""
+    from repro.data.loader import ShardedLoader
+    from repro.data.synthetic import SyntheticStream
+    from repro.optim.adamw import AdamWConfig
+    from repro.train.loop import train_loop
+    from repro.train.step import jit_train_step, state_shardings
+    model, ck, saved = _resumable_mamba2(hdfs, rules, 6)
+    p2, o2, hist = train_loop(model, batch=2, seq_len=16, steps=2,
+                              log_fn=lambda *_: None, checkpointer=ck,
+                              resume_from=6)
+    restored = ck.restore(6, *saved)
+    params, opt = jax.device_put(tuple(restored), state_shardings(model))
+    step_fn = jit_train_step(model, AdamWConfig(), 2)
+    loader = ShardedLoader(SyntheticStream(model.cfg.vocab_size, 0),
+                           model.rules, 2, 16)
+    losses = []
+    for step in (6, 7):
+        params, opt, metrics = step_fn(params, opt, loader(step))
+        losses.append(float(metrics["loss"]))
+    assert [h["loss"] for h in hist] == losses
+    assert jax.tree.structure((p2, o2)) == jax.tree.structure((params, opt))
+    for a, b in zip(jax.tree.leaves((p2, o2)), jax.tree.leaves((params, opt))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("lower_only", [False, True])
+def test_resumed_train_loop_runs_a_plain_jit_step(hdfs, rules, monkeypatch,
+                                                  lower_only):
+    """``loop.jit_train_step`` may return a plain ``jax.jit`` with donated
+    state and no shardings (as the benchmark's planted faults do): the
+    resumed loop compiles it from shapes and runs it for every step.  It
+    relies on ``.lower(...).compile()`` alone: the step's executable runs
+    every step even where the returned object has nothing else."""
+    from functools import partial
+    from types import SimpleNamespace
+    from repro.data.loader import ShardedLoader
+    from repro.data.synthetic import SyntheticStream
+    from repro.train import loop
+    model, ck, saved = _resumable_mamba2(hdfs, rules, 6)
+
+    @partial(jax.jit, donate_argnums=(0, 1))
+    def plain(params, opt, batch):
+        loss = jnp.sum(batch["tokens"]).astype(jnp.float32)
+        return params, dict(opt, step=opt["step"] + 1), \
+            {"loss": loss, "grad_norm": jnp.zeros((), jnp.float32)}
+
+    step_fn = SimpleNamespace(lower=plain.lower) if lower_only else plain
+    monkeypatch.setattr(loop, "jit_train_step", lambda *a, **kw: step_fn)
+    p2, o2, hist = loop.train_loop(model, batch=2, seq_len=16, steps=3,
+                                   log_every=1, log_fn=lambda *_: None,
+                                   checkpointer=ck, resume_from=6)
+    loader = ShardedLoader(SyntheticStream(model.cfg.vocab_size, 0),
+                           model.rules, 2, 16)
+    assert [(h["step"], h["loss"]) for h in hist] == [
+        (s, float(np.sum(np.asarray(loader(s)["tokens"])))) for s in (6, 7, 8)]
+    assert int(o2["step"]) == int(saved[1]["step"]) + 3
+    for a, b in zip(jax.tree.leaves(p2), jax.tree.leaves(saved[0])):
+        np.testing.assert_array_equal(np.asarray(a), b)
+
 def test_restore_into_model_params(hdfs, rules):
     """Round-trip real model params and keep training."""
     from repro.configs import get_tiny

@@ -245,8 +245,9 @@ class TestSpans:
 
 def test_train_loop_resume_records_its_spans(tmp_path, rules):
     """A resumed train_loop through a real Checkpointer records each
-    ckpt.* and train.* span the expected number of times, and compiles
-    by program."""
+    ckpt.* and train.* span the expected number of times, compiles the
+    train step once inside ``train.compile`` (no warm-up, no compile or
+    cache read inside a ``train.step``), and compiles by program."""
     import jax
     from repro.ckpt.checkpoint import Checkpointer
     from repro.configs import get_tiny
@@ -260,19 +261,25 @@ def test_train_loop_resume_records_its_spans(tmp_path, rules):
     ck = Checkpointer(HdfsCluster(tmp_path / "hdfs", num_groups=4,
                                   block_size=1 << 16), width=4)
     ck.save(7, params, adamw_init(params))
-    snap = SPANS.snapshot()
-    train_loop(model, batch=2, seq_len=16, steps=3, log_fn=lambda *_: None,
-               checkpointer=ck, resume_from=7)
-    rows = SPANS.since(snap)
-    once = ["train.restore", "train.place.params", "train.build",
-            "train.warmup.copy", "train.warmup.zeros", "train.warmup.step",
-            "train.opt_wait", "train.place.opt", "ckpt.plan",
-            "ckpt.wave.params", "ckpt.assemble", "ckpt.wave.opt"]
-    assert {n: rows[n].count for n in once} == dict.fromkeys(once, 1)
-    assert rows["train.step"].count == 3
-    assert rows["ckpt.wave.params"].parent == "train.restore"
-    assert rows["ckpt.wave.opt"].parent == "train.restore"
-    compiles = {n: r for n, r in rows.items() if n.startswith("compile.")}
-    assert compiles and all(r.count > 0 for r in compiles.values())
-    assert rows["train.warmup.step"].self_s < \
-        rows["train.warmup.step"].total_s
+    for _ in range(2):
+        snap = SPANS.snapshot()
+        train_loop(model, batch=2, seq_len=16, steps=3,
+                   log_fn=lambda *_: None, checkpointer=ck, resume_from=7)
+        rows = SPANS.since(snap)
+        once = ["train.restore", "train.place.params", "train.build",
+                "train.compile", "train.opt_wait", "train.place.opt",
+                "ckpt.plan", "ckpt.wave.params", "ckpt.assemble",
+                "ckpt.wave.opt"]
+        assert {n: rows[n].count for n in once} == dict.fromkeys(once, 1)
+        assert rows["train.step"].count == 3
+        assert not [n for n in rows if n.startswith("train.warmup.")]
+        assert rows["ckpt.wave.params"].parent == "train.restore"
+        assert rows["ckpt.wave.opt"].parent == "train.restore"
+        compiles = {n: r for n, r in rows.items()
+                    if n.startswith(("compile.", "compile_cached."))}
+        assert compiles and all(r.count > 0 for r in compiles.values())
+        assert all(r.parent != "train.step" for r in compiles.values())
+        step = {n: r for n, r in compiles.items() if "train_step" in n}
+        assert step["compile.jit(train_step)"].count == 1
+        assert {r.parent for r in step.values()} == {"train.compile"}
+        assert rows["train.compile"].self_s < rows["train.compile"].total_s
